@@ -242,7 +242,17 @@ def run_sharded(csv_rows: list, quick: bool = False):
     """Measured 1-vs-8-shard serving speedup per estimator, recorded next
     to the Eq. 15 Amdahl bound (paper Table 3's theoretical column for the
     sharded path).  Spawns a forced-8-device subprocess; see module
-    docstring for why the CPU number is a floor, not a speedup claim."""
+    docstring for why the CPU number is a floor, not a speedup claim.
+    CPU-only: the child runs on forced host devices, and its record is
+    written under the parent's backend name, so on an accelerator the
+    record would carry that label on CPU numbers."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "run_sharded measures forced host devices in a CPU child and "
+            f"cannot run under the {jax.default_backend()!r} backend: its "
+            "record would label CPU numbers as device numbers")
     root = Path(__file__).resolve().parents[1]
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

@@ -408,11 +408,8 @@ def sharded_table(path: Path = BENCH_SHARDED) -> str:
 
 
 def _backend_name() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:
-        return "unknown"
+    import jax
+    return jax.default_backend()
 
 
 def fused_topk_table(path: Path = BENCH_FUSED_TOPK) -> str:

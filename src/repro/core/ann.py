@@ -154,6 +154,32 @@ def fit_ivf_pq(X, y, *, n_cells: int, m: int, n_codes: int,
                      n_class=n_class)
 
 
+def probe_candidates(params: ANNParams, X, k: int, nprobe: int, *,
+                     refine: int = 0, policy=None,
+                     path: Optional[str] = None):
+    """The ADC stage's inputs for a query batch: probe -> gather inverted
+    lists.  Returns (query LUTs (B, m*n_codes) int32, candidate codes
+    (B, L, m) int8, candidate ids (B, L) int32 with -1 padding, and
+    ``want``, the number of ADC survivors the classify keeps)."""
+    B = X.shape[0]
+    C = params.centroids.shape[0]
+    p = min(nprobe, C)
+
+    # coarse probe: the SAME fused distance->top-k kernel exact kNN uses,
+    # over the C cell centroids instead of the N reference rows
+    _, cells = dispatch.distance_topk(params.centroids, X, p,
+                                      policy=policy, path=path)   # (B, p)
+    cand = params.cell_ids[cells].reshape(B, p * params.cell_ids.shape[1])
+    want = max(k, min(refine, cand.shape[1]) if refine > 0 else 0)
+    if cand.shape[1] < want:               # degenerate tiny indexes
+        cand = jnp.pad(cand, ((0, 0), (0, want - cand.shape[1])),
+                       constant_values=-1)
+
+    qlut = build_query_luts(X, params.codebooks)       # (B, m*n_codes)
+    cand_codes = params.codes[jnp.maximum(cand, 0)]    # (B, L, m) int8
+    return qlut, cand_codes, cand, want
+
+
 def ann_classify_batch(params: ANNParams, X, k: int, nprobe: int, *,
                        refine: int = 0, policy=None,
                        path: Optional[str] = None):
@@ -169,24 +195,8 @@ def ann_classify_batch(params: ANNParams, X, k: int, nprobe: int, *,
     below its 255-step resolution — the short exact pass touches only
     ``refine`` raw rows per query, so the N-proportional work stays on
     the codes (DESIGN.md §10)."""
-    B = X.shape[0]
-    C = params.centroids.shape[0]
-    m = params.codebooks.shape[0]
-    p = min(nprobe, C)
-
-    # coarse probe: the SAME fused distance->top-k kernel exact kNN uses,
-    # over the C cell centroids instead of the N reference rows
-    _, cells = dispatch.distance_topk(params.centroids, X, p,
-                                      policy=policy, path=path)   # (B, p)
-    cand = params.cell_ids[cells].reshape(B, p * params.cell_ids.shape[1])
-    want = max(k, min(refine, cand.shape[1]) if refine > 0 else 0)
-    if cand.shape[1] < want:               # degenerate tiny indexes
-        cand = jnp.pad(cand, ((0, 0), (0, want - cand.shape[1])),
-                       constant_values=-1)
-
-    qlut = build_query_luts(X, params.codebooks)       # (B, m*n_codes)
-    cand_codes = params.codes[jnp.maximum(cand, 0)]    # (B, L, m) int8
-
+    qlut, cand_codes, cand, want = probe_candidates(
+        params, X, k, nprobe, refine=refine, policy=policy, path=path)
     _, pos = dispatch.adc_topk(qlut, cand_codes, cand, want,
                                policy=policy, path=path)       # (B, want)
     nbr = jnp.take_along_axis(cand, pos, axis=1)       # global ids

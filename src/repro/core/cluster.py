@@ -26,6 +26,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.distribution import pad_to_multiple
@@ -33,7 +34,6 @@ from repro.core.gnb import GNBModel, _log_gaussian
 from repro.core.knn import KNNModel, sq_distances
 from repro.core.kmeans import KMeansState, _pairwise_sq_dist
 from repro.core.topk import selection_topk_smallest
-from repro.sharding.compat import shard_map as _shard_map
 
 # padding rows for a sharded kNN reference set: large enough that padded
 # rows can never enter a top-k (squared distance >= ~1e34), small enough

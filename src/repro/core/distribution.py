@@ -22,9 +22,9 @@ from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding.compat import shard_map as _shard_map
 
 
 # ---------------------------------------------------------------------------

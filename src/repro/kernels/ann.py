@@ -38,14 +38,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import pad_dim, resolve_interpret
+from repro.kernels.topk_select import merge_topk
+
 _IMAX = jnp.iinfo(jnp.int32).max
-_COL_MULT = 8                  # candidate-block multiple (f32/int32 sublane)
+_LANES = 128                   # candidate-block multiple (one vreg of lanes)
+_ROW_MULT = 32                 # query-row multiple (int8 sublane tile)
 _QSTEPS = 255                  # LUT values live on the 0..255 integer step
 _VMEM_BUDGET = 16 * 2 ** 20
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def adc_dmax(m: int) -> int:
@@ -60,23 +60,29 @@ def packed_cols_limit(m: int) -> int:
     return (2 ** 31 - 1) // (adc_dmax(m) + 1)
 
 
+def _lane_pad(n: int) -> int:
+    return -(-n // _LANES) * _LANES
+
+
 def adc_working_set_bytes(bl: int, q: int, m: int, n_codes: int,
                           k: int) -> int:
-    """VMEM working set of one ADC grid step: the resident (Q, m*n_codes)
-    int32 LUT, double-buffered int8 code and int32 id tiles, the (Q, bl)
-    key tile, and the (Q, k) x4 selection scratch + merge candidates +
-    outputs."""
-    return q * m * n_codes * 4 + 2 * (q * bl * m) + 2 * (q * bl * 4) \
-        + q * bl * 4 + 4 * q * k * 4 + 2 * q * 2 * k * 4 + 2 * q * k * 4
+    """VMEM working set of one ADC grid step: the resident (Q, m*ncp)
+    int32 LUT (each subspace lane-padded to ncp), double-buffered int8
+    code and int32 id tiles, the (Q, bl) distance scratch and the key
+    tile plus its selection temporaries, and the (Q, k) accumulator
+    scratch + outputs."""
+    kp = _lane_pad(k)
+    return q * m * _lane_pad(n_codes) * 4 + 2 * (m * q * bl) \
+        + 2 * (q * bl * 4) + 4 * (q * bl * 4) + 4 * q * kp * 4
 
 
 def adc_block_cols(L: int, q: int, m: int, n_codes: int, k: int,
                    budget: int = _VMEM_BUDGET) -> int:
-    """Largest multiple-of-8 candidate block under the VMEM budget and
+    """Largest lane-multiple candidate block under the VMEM budget and
     the int32 key-packing bound."""
-    limit = min(packed_cols_limit(m), max(L, _COL_MULT))
-    best = _COL_MULT
-    bl = _COL_MULT
+    limit = min(packed_cols_limit(m), max(L, _LANES))
+    best = _LANES
+    bl = _LANES
     while bl <= limit:
         if adc_working_set_bytes(bl, q, m, n_codes, k) <= budget:
             best = bl
@@ -84,27 +90,9 @@ def adc_block_cols(L: int, q: int, m: int, n_codes: int, k: int,
     return best
 
 
-def _pad_cols(x, mult: int, value=0):
-    pad = (-x.shape[1]) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[1] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
-
-
-def _pad_rows(x, mult: int, value=0):
-    pad = (-x.shape[0]) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[0] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
-
-
 def _adc_topk_kernel(lut_ref, codes_ref, ids_ref, vals_ref, idx_ref,
-                     acc_v, acc_i, tile_v, tile_i, *, k: int, bl: int,
-                     m: int, n_codes: int):
+                     acc_v, acc_i, dist_ref, *, k: int, bl: int, m: int,
+                     ncp: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -112,71 +100,59 @@ def _adc_topk_kernel(lut_ref, codes_ref, ids_ref, vals_ref, idx_ref,
         acc_v[...] = jnp.full_like(acc_v, _IMAX)
         acc_i[...] = jnp.zeros_like(acc_i)
 
-    # ADC hot loop: m LUT gathers + adds per candidate.  Codes are stored
-    # int8 as (code - 128); the +128 restore and the per-subspace LUT row
-    # offset fold into one gather index.
-    codes = codes_ref[...].astype(jnp.int32) + 128      # (Q, bl*m) 0..255
-    q = codes.shape[0]
-    sub = jax.lax.broadcasted_iota(jnp.int32, (q, bl * m), 1) % m
-    gathered = jnp.take_along_axis(lut_ref[...], codes + sub * n_codes,
-                                   axis=1)              # (Q, bl*m)
-    dist = jnp.sum(gathered.reshape(q, bl, m), axis=2)  # (Q, bl)
+    # ADC hot loop: m LUT lookups + adds per candidate, one chunk of at
+    # most 128 candidate lanes at a time.  Codes are stored int8 as
+    # (code - 128).  Each subspace's LUT row is lane-padded to ncp, so a
+    # lookup is a within-vreg lane gather from one 128-wide LUT slice
+    # (code & 127) selected by code >> 7 — the gather form Mosaic lowers.
+    q = ids_ref.shape[0]
+    lc = min(bl, _LANES)
+
+    def chunk(t, carry):
+        lo = pl.multiple_of(t * lc, lc)
+        dist = jnp.zeros((q, lc), jnp.int32)
+        for j in range(m):
+            code = codes_ref[j, :, pl.ds(lo, lc)].astype(jnp.int32) + 128
+            low, high = code & (_LANES - 1), code >> 7
+            for h in range(ncp // _LANES):
+                col = j * ncp + h * _LANES
+                lut = lut_ref[:, col:col + _LANES]           # (Q, 128)
+                got = jnp.take_along_axis(lut, low, axis=1)  # (Q, lc)
+                dist = dist + jnp.where(high == h, got, 0)
+        dist_ref[:, pl.ds(lo, lc)] = dist
+        return carry
+
+    jax.lax.fori_loop(0, bl // lc, chunk, 0)
 
     # invalid candidates (ragged-cell padding, id < 0) take the DMAX
     # sentinel in VALUE space so short candidate lists stay bit-equal to
     # the dense oracle (its tail is the same DMAX entries)
-    dist = jnp.where(ids_ref[...] < 0, adc_dmax(m), dist)
+    dist = jnp.where(ids_ref[...] < 0, adc_dmax(m), dist_ref[...])
 
-    # pack (dist, lane) into one int32 key — unique by construction, so
-    # each selection pass is a masked min with no tie-break machinery
+    # pack (dist, lane) into one int32 key — unique by construction — and
+    # fold the tile into the running k-smallest: accumulator first on
+    # equal distances, then the smallest lane, i.e. the smallest global
+    # candidate position — the same stable rule as lax.top_k
     lane = jax.lax.broadcasted_iota(jnp.int32, (q, bl), 1)
-    key = dist * bl + lane
-
-    def tile_pass(j, carry):
-        kk, = carry
-        mn = jnp.min(kk, axis=1)                        # (Q,)
-        tile_v[:, j] = mn // bl                         # ADC distance
-        tile_i[:, j] = i * bl + (mn % bl)               # global cand pos
-        return (jnp.where(kk == mn[:, None], _IMAX, kk),)
-
-    jax.lax.fori_loop(0, k, tile_pass, (key,))
-
-    # merge two sorted k-lists (running accumulator, tile top-k); columns
-    # ordered accumulator-first and ascending-position within each list,
-    # so "first position attaining the min" = smallest global candidate
-    # position — the same stable rule as lax.top_k (kernels/quantized.py)
-    width = 2 * k
-    cand_v = jnp.concatenate([acc_v[...], tile_v[...]], axis=1)
-    cand_i = jnp.concatenate([acc_i[...], tile_i[...]], axis=1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (q, width), 1)
-
-    def merge_pass(j, carry):
-        cv, = carry
-        mn = jnp.min(cv, axis=1)
-        first = jnp.min(jnp.where(cv == mn[:, None], cols, width), axis=1)
-        sel = jnp.sum(jnp.where(cols == first[:, None], cand_i, 0), axis=1)
-        acc_v[:, j] = mn
-        acc_i[:, j] = sel
-        return (jnp.where(cols == first[:, None], _IMAX, cv),)
-
-    jax.lax.fori_loop(0, k, merge_pass, (cand_v,))
-
-    vals_ref[...] = acc_v[...]
-    idx_ref[...] = acc_i[...]
+    v, ix = merge_topk(acc_v[...], acc_i[...], dist * bl + lane, i * bl, k,
+                       fill=_IMAX, packed_bn=bl)
+    acc_v[...] = v
+    acc_i[...] = ix
+    vals_ref[...] = v
+    idx_ref[...] = ix
 
 
-def _adc_topk_call(lut, codes_flat, ids, k: int, *, bl: int, m: int,
-                   n_codes: int, interpret: bool):
+def _adc_topk_call(lut, codes_t, ids, k: int, *, bl: int, m: int,
+                   ncp: int, interpret: bool):
     Q, Lp = ids.shape
-    kernel = functools.partial(_adc_topk_kernel, k=k, bl=bl, m=m,
-                               n_codes=n_codes)
+    kernel = functools.partial(_adc_topk_kernel, k=k, bl=bl, m=m, ncp=ncp)
     return pl.pallas_call(
         kernel,
         grid=(Lp // bl,),
         in_specs=[
-            pl.BlockSpec((Q, m * n_codes), lambda i: (0, 0)),  # resident LUT
-            pl.BlockSpec((Q, bl * m), lambda i: (0, i)),       # streams, int8
-            pl.BlockSpec((Q, bl), lambda i: (0, i)),           # streams, ids
+            pl.BlockSpec((Q, m * ncp), lambda i: (0, 0)),    # resident LUT
+            pl.BlockSpec((m, Q, bl), lambda i: (0, 0, i)),   # streams, int8
+            pl.BlockSpec((Q, bl), lambda i: (0, i)),         # streams, ids
         ],
         out_specs=(pl.BlockSpec((Q, k), lambda i: (0, 0)),
                    pl.BlockSpec((Q, k), lambda i: (0, 0))),
@@ -184,10 +160,9 @@ def _adc_topk_call(lut, codes_flat, ids, k: int, *, bl: int, m: int,
                    jax.ShapeDtypeStruct((Q, k), jnp.int32)),
         scratch_shapes=[pltpu.VMEM((Q, k), jnp.int32),
                         pltpu.VMEM((Q, k), jnp.int32),
-                        pltpu.VMEM((Q, k), jnp.int32),
-                        pltpu.VMEM((Q, k), jnp.int32)],
+                        pltpu.VMEM((Q, bl), jnp.int32)],
         interpret=interpret,
-    )(lut, codes_flat, ids)
+    )(lut, codes_t, ids)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bl", "interpret"))
@@ -197,7 +172,8 @@ def adc_topk(qlut, codes, cand_ids, k: int, *, bl: int | None = None,
     (Q, L, m) int8 (stored code-128), candidate ids (Q, L) int32 (< 0 =
     invalid) -> (ADC distances (Q, k) int32, candidate POSITIONS (Q, k)
     int32 into the L axis), ascending, smallest-position ties — bit-equal
-    to ``ref_adc_topk``."""
+    to ``ref_adc_topk``.  ``bl`` defaults to a multiple of 128 lanes;
+    smaller explicit blocks run in interpret mode only."""
     Q, L, m = codes.shape
     n_codes = qlut.shape[1] // m
     assert qlut.shape == (Q, m * n_codes), (qlut.shape, codes.shape)
@@ -205,17 +181,21 @@ def adc_topk(qlut, codes, cand_ids, k: int, *, bl: int | None = None,
     assert codes.dtype == jnp.int8, codes.dtype
     assert 1 <= k <= L, (k, L)
     if bl is None:
-        bl = adc_block_cols(L, max(Q, 8), m, n_codes, k)
+        bl = adc_block_cols(L, max(Q, _ROW_MULT), m, n_codes, k)
     bl = min(bl, packed_cols_limit(m))
-    bl = max(_COL_MULT, (min(bl, max(L, _COL_MULT)) // _COL_MULT)
-             * _COL_MULT)
+    mult = _LANES if bl >= _LANES else 8
+    bl = max(mult, (min(bl, max(L, mult)) // mult) * mult)
     assert (adc_dmax(m) + 1) * bl <= 2 ** 31 - 1, (m, bl)  # key cannot wrap
-    interpret = _on_cpu() if interpret is None else interpret
-    lut = _pad_rows(jnp.asarray(qlut, jnp.int32), 8)
-    ids = _pad_rows(_pad_cols(cand_ids, bl, value=-1), 8, value=-1)
-    cf = _pad_rows(_pad_cols(codes, bl).reshape(codes.shape[0], -1), 8)
-    vals, pos = _adc_topk_call(lut, cf, ids, k, bl=bl, m=m,
-                               n_codes=n_codes, interpret=interpret)
+    interpret = resolve_interpret(interpret)
+    ncp = _lane_pad(n_codes)
+    lut = pad_dim(jnp.asarray(qlut, jnp.int32).reshape(Q, m, n_codes),
+                  ncp, 2, 0).reshape(Q, m * ncp)
+    lut = pad_dim(lut, _ROW_MULT, 0, 0)
+    ids = pad_dim(pad_dim(cand_ids, bl, 1, -1), _ROW_MULT, 0, -1)
+    codes_t = pad_dim(pad_dim(jnp.transpose(codes, (2, 0, 1)), bl, 2, 0),
+                      _ROW_MULT, 1, 0)                     # (m, Qp, Lp)
+    vals, pos = _adc_topk_call(lut, codes_t, ids, k, bl=bl, m=m, ncp=ncp,
+                               interpret=interpret)
     return vals[:Q], pos[:Q]
 
 

@@ -578,10 +578,10 @@ def _ann_ref(qlut, codes, cand_ids, k, *, bl=None, interpret=None):
 @selector("ann", "adc_topk")
 def _ann_select(*, Q, L, m, n_codes, k, policy=None, budget=VMEM_BUDGET):
     # the streaming kernel keeps the (Q, m*n_codes) LUT resident; if even
-    # the minimum bl=8 candidate block overflows VMEM (huge Q*m*n_codes),
-    # fall back to the dense oracle
+    # its minimum 128-lane candidate block (queries padded to 32 rows)
+    # overflows VMEM (huge Q*m*n_codes), fall back to the dense oracle
     from repro.kernels import ann as annk
-    if annk.adc_working_set_bytes(8, max(Q, 8), m, n_codes, k) <= budget:
+    if annk.adc_working_set_bytes(128, max(Q, 32), m, n_codes, k) <= budget:
         return "fused"
     return "ref"
 

@@ -21,7 +21,7 @@ def _dist_kernel(a_ref, c_ref, o_ref):
     an = jnp.sum(a * a, axis=1, keepdims=True)  # (bn, 1)
     cn = jnp.sum(c * c, axis=1)[None, :]        # (1, K)
     cross = jax.lax.dot_general(
-        a, c, (((1,), (1,)), ((), ())),
+        a, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)     # (bn, K) on the MXU
     o_ref[...] = (an - 2.0 * cross + cn).astype(o_ref.dtype)
 

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.topk_select import merge_topk
+
 _INF = float("inf")
 
 
@@ -40,7 +42,7 @@ def _sq_dist_tile(a, c):
     an = jnp.sum(a * a, axis=1, keepdims=True)   # (bn, 1)
     cn = jnp.sum(c * c, axis=1)[None, :]         # (1, Q)
     cross = jax.lax.dot_general(
-        a, c, (((1,), (1,)), ((), ())),
+        a, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)      # (bn, Q) on the MXU
     return an - 2.0 * cross + cn
 
@@ -60,28 +62,14 @@ def _fused_kernel(a_ref, c_ref, vals_ref, idx_ref, acc_v, acc_i,
     tile = jnp.where(gidx < n_valid, tile, _INF)          # mask padded rows
 
     # merge the tile into the running k-smallest: k masked-min passes over
-    # [accumulator | tile] — the in-VMEM Selection Sort of the paper's OP2
-    width = k + bn
-    cand_v = jnp.concatenate([acc_v[...], tile], axis=1)  # (Q, k+bn)
-    cand_i = jnp.concatenate([acc_i[...], gidx], axis=1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (q, width), 1)
-
-    def pass_body(j, carry):
-        cv, = carry
-        m = jnp.min(cv, axis=1)                           # (Q,)
-        is_min = cv == m[:, None]
-        first = jnp.min(jnp.where(is_min, cols, width), axis=1)
-        sel = jnp.sum(jnp.where(cols == first[:, None], cand_i, 0), axis=1)
-        acc_v[:, j] = m.astype(acc_v.dtype)
-        acc_i[:, j] = sel.astype(jnp.int32)
-        cv = jnp.where(cols == first[:, None], _INF, cv)
-        return (cv,)
-
-    jax.lax.fori_loop(0, k, pass_body, (cand_v,))
+    # accumulator and tile — the in-VMEM Selection Sort of the paper's OP2
+    v, ix = merge_topk(acc_v[...], acc_i[...], tile, i * bn, k, fill=_INF)
+    acc_v[...] = v
+    acc_i[...] = ix
 
     # constant out block: every step revises it, the last step's value lands
-    vals_ref[...] = acc_v[...].astype(vals_ref.dtype)
-    idx_ref[...] = acc_i[...]
+    vals_ref[...] = v.astype(vals_ref.dtype)
+    idx_ref[...] = ix
 
 
 def distance_topk(a, c, k: int, *, bn: int = 256, n_valid: int | None = None,

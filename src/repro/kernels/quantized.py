@@ -40,6 +40,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import pad_dim, resolve_interpret
+from repro.kernels.topk_select import merge_topk
+
 _IMAX = jnp.iinfo(jnp.int32).max
 _QMAX = 127                     # symmetric int8 lattice: values in [-127, 127]
 _ROW_MULT = 32                  # int8 sublane tile (see pallas guide)
@@ -51,10 +54,6 @@ _ROW_MULT = 32                  # int8 sublane tile (see pallas guide)
 # beyond it the kernel would silently wrap, not degrade.
 _MAX_D = 832
 _VMEM_BUDGET = 16 * 2 ** 20
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -84,16 +83,6 @@ def lattice_sq_norms(q):
     return jnp.sum(qi * qi, axis=1)
 
 
-def _pad_rows(x, mult: int, value=0):
-    n = x.shape[0]
-    pad = (-n) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] + [(0, 0)] * (x.ndim - 1)
-    widths[0] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
-
-
 # ---------------------------------------------------------------------------
 # Block autotuning — the int8 analogue of ops.fused_topk_block_rows
 # ---------------------------------------------------------------------------
@@ -102,7 +91,7 @@ def _pad_rows(x, mult: int, value=0):
 def quant_topk_working_set_bytes(bn: int, d: int, q: int, k: int) -> int:
     """VMEM working set of one quant fused distance->top-k grid step: the
     double-buffered int8 (bn, d) A tile, resident int8 (Q, d) C, the
-    (Q, bn) int32 packed-key tile, tile top-k + merge candidates, and the
+    (Q, bn) int32 packed-key tile, the (Q, k) selection carries, and the
     (Q, k) x2 accumulator scratch + outputs.  int8 shrinks the two
     feature-carrying terms 4x vs ``ops.fused_topk_working_set_bytes``."""
     return (2 * bn * d) + q * d + bn * q * 4 + 4 * q * k * 4 \
@@ -155,8 +144,7 @@ def _int_cross(a8, b8):
 
 
 def _quant_topk_kernel(a_ref, c_ref, vals_ref, idx_ref, acc_v, acc_i,
-                       tile_v, tile_i, *, k: int, bn: int, n_valid: int,
-                       off: int):
+                       *, k: int, bn: int, n_valid: int, off: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -167,10 +155,13 @@ def _quant_topk_kernel(a_ref, c_ref, vals_ref, idx_ref, acc_v, acc_i,
     # int8 GEMM hot loop: partial distance an - 2*cross, offset to >= 0.
     # The query norm ||c||^2 is rank-irrelevant per query and is restored
     # by the wrapper outside the stream.
+    # The tile is built row-major (bn, Q) and transposed once, as in the
+    # fp32 kernel: broadcasting the (bn,) row norms along lanes instead
+    # makes Mosaic spill past the scoped VMEM limit at Q=256.
     aq = a_ref[...]                                     # (bn, d) int8
-    cross = _int_cross(c_ref[...], aq)                  # (Q, bn) int32
+    cross = _int_cross(aq, c_ref[...])                  # (bn, Q) int32
     an = lattice_sq_norms(aq)                           # (bn,) int32
-    dist = an[None, :] - 2 * cross + off                # (Q, bn) >= 0
+    dist = (an[:, None] - 2 * cross + off).T            # (Q, bn) >= 0
     q = dist.shape[0]
 
     # pack (dist, lane) into one int32 key — unique by construction, so
@@ -179,37 +170,15 @@ def _quant_topk_kernel(a_ref, c_ref, vals_ref, idx_ref, acc_v, acc_i,
     key = dist * bn + lane
     key = jnp.where(i * bn + lane < n_valid, key, _IMAX)
 
-    def tile_pass(j, carry):
-        kk, = carry
-        m = jnp.min(kk, axis=1)                         # (Q,)
-        tile_v[:, j] = m // bn                          # offset dist
-        tile_i[:, j] = i * bn + (m % bn)                # global row index
-        return (jnp.where(kk == m[:, None], _IMAX, kk),)
-
-    jax.lax.fori_loop(0, k, tile_pass, (key,))
-
-    # merge two sorted k-lists (running accumulator, tile top-k).  Columns
-    # are ordered accumulator-first and ascending-index within each list,
-    # so "first position attaining the min" = smallest global row index —
-    # the same stable rule as the fp32 fused kernel and lax.top_k.
-    width = 2 * k
-    cand_v = jnp.concatenate([acc_v[...], tile_v[...]], axis=1)
-    cand_i = jnp.concatenate([acc_i[...], tile_i[...]], axis=1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (q, width), 1)
-
-    def merge_pass(j, carry):
-        cv, = carry
-        m = jnp.min(cv, axis=1)
-        first = jnp.min(jnp.where(cv == m[:, None], cols, width), axis=1)
-        sel = jnp.sum(jnp.where(cols == first[:, None], cand_i, 0), axis=1)
-        acc_v[:, j] = m
-        acc_i[:, j] = sel
-        return (jnp.where(cols == first[:, None], _IMAX, cv),)
-
-    jax.lax.fori_loop(0, k, merge_pass, (cand_v,))
-
-    vals_ref[...] = acc_v[...]
-    idx_ref[...] = acc_i[...]
+    # fold the tile into the running k-smallest: accumulator first on
+    # equal distances, then the smallest lane — the smallest global row
+    # index, the same stable rule as the fp32 fused kernel and lax.top_k
+    v, ix = merge_topk(acc_v[...], acc_i[...], key, i * bn, k, fill=_IMAX,
+                       packed_bn=bn)
+    acc_v[...] = v
+    acc_i[...] = ix
+    vals_ref[...] = v
+    idx_ref[...] = ix
 
 
 def _quant_topk_call(ap, cp, k: int, *, bn: int, n_valid: int, off: int,
@@ -230,8 +199,6 @@ def _quant_topk_call(ap, cp, k: int, *, bn: int, n_valid: int, off: int,
         out_shape=(jax.ShapeDtypeStruct((Q, k), jnp.int32),
                    jax.ShapeDtypeStruct((Q, k), jnp.int32)),
         scratch_shapes=[pltpu.VMEM((Q, k), jnp.int32),
-                        pltpu.VMEM((Q, k), jnp.int32),
-                        pltpu.VMEM((Q, k), jnp.int32),
                         pltpu.VMEM((Q, k), jnp.int32)],
         interpret=interpret,
     )(ap, cp)
@@ -258,10 +225,10 @@ def distance_topk_q8(aq, cq, k: int, *, bn: int | None = None,
     bn = min(bn, packed_rows_limit(d))
     bn = max(_ROW_MULT, (min(bn, max(N, _ROW_MULT)) // _ROW_MULT) * _ROW_MULT)
     assert dist_span(d) * bn <= 2 ** 31 - 1, (d, bn)   # key cannot wrap
-    interpret = _on_cpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     off = 2 * d * _QMAX * _QMAX
-    ap = _pad_rows(aq, bn)
-    cp = _pad_rows(cq, 8)
+    ap = pad_dim(aq, bn, 0, 0)
+    cp = pad_dim(cq, 8, 0, 0)
     vals, idx = _quant_topk_call(ap, cp, k, bn=bn, n_valid=N, off=off,
                                  interpret=interpret)
     cn = lattice_sq_norms(cp)                           # restore ||c||^2
@@ -285,14 +252,14 @@ def ref_distance_topk_q8(aq, cq, k: int):
 # ---------------------------------------------------------------------------
 
 
-def _quant_argmin_kernel(a_ref, c_ref, val_ref, idx_ref, *, off: int,
-                         kp: int, packed: bool):
+def _quant_argmin_kernel(a_ref, c_ref, cn_ref, val_ref, idx_ref, *,
+                         off: int, kp: int, packed: bool):
     aq = a_ref[...]                                     # (bn, d) int8
-    cq = c_ref[...]                                     # (K, d) int8
-    cross = _int_cross(aq, cq)                          # (bn, K) int32
-    cn = lattice_sq_norms(cq)                           # (K,) int32
-    # the row norm ||a||^2 is rank-irrelevant per row; restored outside
-    dist = cn[None, :] - 2 * cross + off                # (bn, K) >= 0
+    cross = _int_cross(aq, c_ref[...])                  # (bn, K) int32
+    # the row norm ||a||^2 is rank-irrelevant per row; restored outside.
+    # The centroid norms arrive as a (1, K) row: reducing them in-kernel
+    # and broadcasting the result along lanes never finishes compiling.
+    dist = cn_ref[...] - 2 * cross + off                # (bn, K) >= 0
     bn, K = dist.shape
     if packed:
         cols = jax.lax.broadcasted_iota(jnp.int32, (bn, K), 1)
@@ -318,14 +285,14 @@ def distance_argmin_q8(aq, cq, *, bn: int = 1024,
     assert aq.dtype == jnp.int8 and cq.dtype == jnp.int8, (aq.dtype, cq.dtype)
     if d > _MAX_D:
         raise ValueError(f"quant argmin supports d <= {_MAX_D}, got {d}")
-    interpret = _on_cpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     off = 2 * d * _QMAX * _QMAX
     kp = 1
     while kp < K:
         kp *= 2
     packed = dist_span(d) * kp <= 2 ** 31 - 1
     bn = max(_ROW_MULT, (min(bn, max(N, _ROW_MULT)) // _ROW_MULT) * _ROW_MULT)
-    ap = _pad_rows(aq, bn)
+    ap = pad_dim(aq, bn, 0, 0)
     kernel = functools.partial(_quant_argmin_kernel, off=off, kp=kp,
                                packed=packed)
     vals, idx = pl.pallas_call(
@@ -334,13 +301,14 @@ def distance_argmin_q8(aq, cq, *, bn: int = 1024,
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
             pl.BlockSpec((K, d), lambda i: (0, 0)),
+            pl.BlockSpec((1, K), lambda i: (0, 0)),
         ],
         out_specs=(pl.BlockSpec((bn, 1), lambda i: (i, 0)),
                    pl.BlockSpec((bn, 1), lambda i: (i, 0))),
         out_shape=(jax.ShapeDtypeStruct((ap.shape[0], 1), jnp.int32),
                    jax.ShapeDtypeStruct((ap.shape[0], 1), jnp.int32)),
         interpret=interpret,
-    )(ap, cq)
+    )(ap, cq, lattice_sq_norms(cq)[None, :])
     an = lattice_sq_norms(aq)                           # restore ||a||^2
     return (vals[:N, 0] - off) + an, idx[:N, 0]
 

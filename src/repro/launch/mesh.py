@@ -10,15 +10,15 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.configs.base import MeshConfig
-from repro.sharding.compat import make_mesh as _compat_make_mesh
 
 
 def _mk(shape, axes) -> Mesh:
     n = math.prod(shape)
-    return _compat_make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:n])
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -17,10 +17,10 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init, mlp_is_gated
-from repro.sharding.compat import shard_map as _shard_map
 
 CAPACITY_FACTOR = 1.25
 

@@ -391,17 +391,38 @@ class NonNeuralServeEngine:
                 uniq.append(arm)
         return uniq
 
+    def _has_executor(self, strategy: str) -> bool:
+        """Whether ``strategy`` can be built here: one device always, a
+        mesh strategy only where the algorithm registers a sharded arm
+        for it (ANN has no "reference" partition).  The autotuner skips
+        exactly these arms; any other failure — a kernel the compiler
+        refuses — raises."""
+        if self.mesh is None or strategy == "single":
+            return True
+        op = dispatch.HOT_OPS.get(self.algorithm)
+        return (self.algorithm, op, strategy) in \
+            dispatch.sharded_registered()
+
+    def compiled_text(self, bucket: int, d: int,
+                      dtype=jnp.float32) -> str:
+        """Compiled HLO of the executor serving ``bucket`` for (bucket, d)
+        queries — where a deployment check looks for the Pallas kernels
+        (``tpu_custom_call``) and collectives it expects."""
+        s, p, bn = self._choice(bucket)
+        return self._fn_for(s, p, bn).lower(
+            self._params_for(s), jax.ShapeDtypeStruct((bucket, d), dtype)
+        ).compile().as_text()
+
     def _autotune_bucket(self, size: int, chunk) -> TunedArm:
         """Micro-time every registered arm for one bucket, record the
         winner in ``self.tuned``, and route this bucket through it."""
         static_strategy, static_path = self._static_arm(size)
         measured, static_us = [], None
         for s, p, bn in self._autotune_candidates(size):
-            try:
-                us = self._measure(self._fn_for(s, p, bn),
-                                   self._params_for(s), chunk)
-            except Exception:     # unbuildable arm (e.g. no sharded fn)
+            if not self._has_executor(s):
                 continue
+            us = self._measure(self._fn_for(s, p, bn),
+                               self._params_for(s), chunk)
             measured.append((s, p, bn, us))
             if (s == static_strategy and bn is None
                     and (p is None or p == static_path)):

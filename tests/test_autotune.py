@@ -162,3 +162,39 @@ def test_warmup_without_autotune_leaves_tuned_empty(blobs):
     assert engine.tuned == {}
     s, p, bn = engine._choice(32)
     assert (p, bn) == (None, None)
+
+
+def test_autotune_raises_when_an_arm_fails(blobs):
+    """A failure while timing an arm (a kernel the compiler refuses) must
+    surface, not silently drop the arm from the table."""
+    X, y = blobs
+    engine = _engine(X, y, "knn")
+    cands = engine._autotune_candidates(32)
+
+    def measure(fn, params, chunk, iters=3):
+        if measure.calls == 1:               # the second arm fails
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+        measure.calls += 1
+        return 5.0
+
+    measure.calls = 0
+    engine._measure = measure
+    assert len(cands) >= 2
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        engine.warmup(X[:32], autotune=True)
+
+
+def test_autotune_skips_only_strategies_without_a_sharded_arm(blobs):
+    """The one arm autotune may skip: a mesh strategy the algorithm has no
+    sharded executor for (ANN has no reference partition)."""
+    from repro.launch.mesh import _mk
+
+    X, y = blobs
+    mesh = _mk((1,), ("data",))
+    ann = NonNeuralServeEngine(E.make_fitted("ann", X, y, n_groups=3),
+                               mesh=mesh, max_batch=64)
+    knn = NonNeuralServeEngine(E.make_fitted("knn", X, y, n_groups=3),
+                               mesh=mesh, max_batch=64)
+    assert not ann._has_executor("reference")
+    assert ann._has_executor("query") and ann._has_executor("single")
+    assert knn._has_executor("reference") and knn._has_executor("query")
