@@ -20,8 +20,6 @@ from __future__ import annotations
 import sys
 from pathlib import Path
 
-import numpy as np
-
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 ALGORITHMS = ("knn", "kmeans", "gnb", "gmm", "rf")
@@ -87,8 +85,8 @@ def run(csv_rows: list, quick: bool = False):
                       f"{s['throughput']:8.2f} {s['occupancy']:5.2f} "
                       f"{s['hit_rate']:5.2f} "
                       f"{s['deadline_miss_rate']:5.2f}")
-                mean_batch_us = 1e6 * float(np.mean(
-                    sched.stats.batch_times)) if sched.stats.launches else 0.0
+                mean_batch_us = 1e6 * sched.stats.batch_time_s \
+                    / sched.stats.launches if sched.stats.launches else 0.0
                 csv_rows.append(
                     (f"serving_load/{algo}/r{rate:g}/w{max_wait}",
                      mean_batch_us,

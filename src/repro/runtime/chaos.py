@@ -18,10 +18,11 @@ Two pieces:
   * ``ChaosInjector`` — the hand on the levers: ``attach(scheduler)``
     replaces the scheduler's wall clock with a virtual one (each launch
     costs ``base_batch_time``, straggler ticks cost ``factor`` times
-    that), so ``batch_time`` — and therefore the StepTimer's
-    watch/checkpoint/evict verdicts and every downstream degrade
-    decision — is a pure function of the plan.  ``replay_trace(...,
-    chaos=injector)`` calls ``extra_arrivals``/``apply`` at each tick.
+    that), so ``batch_time`` and ``queue_s`` — and therefore the
+    StepTimer's watch/checkpoint/evict verdicts and every downstream
+    degrade decision — are a pure function of the plan.
+    ``replay_trace(..., chaos=injector)`` calls
+    ``extra_arrivals``/``apply`` at each tick.
 
 Every injected fault lands as a typed ``chaos_*`` event in the
 scheduler's event stream, interleaved with the shed/degrade/breaker
@@ -163,7 +164,9 @@ class ChaosInjector:
     (seconds); straggler ticks cost ``plan.straggler_factor`` times
     that.  The virtual clock is parity-toggled: the scheduler reads it
     once before and once after each launch, so odd reads return the
-    accumulated time and even reads add the launch's scripted cost."""
+    accumulated time and even reads add the launch's scripted cost.  The
+    read that stamps a submit returns the accumulated time and leaves the
+    parity alone."""
 
     def __init__(self, plan: ChaosPlan, *, store=None,
                  base_batch_time: float = 1e-3):
@@ -173,6 +176,7 @@ class ChaosInjector:
         self.sched = None
         self._vt = 0.0
         self._in_launch = False
+        self._submitting = False
         self._stragglers = frozenset(plan.straggler_ticks)
         self._storms = frozenset(plan.storm_ticks)
         self._nan_by_tick: Dict[int, List[int]] = {}
@@ -187,9 +191,21 @@ class ChaosInjector:
         """Install the virtual clock; the plan owns time from here on."""
         self.sched = scheduler
         scheduler.clock = self._clock
+        submit = scheduler.submit
+
+        def stamped_submit(*args, **kwargs):
+            self._submitting = True
+            try:
+                return submit(*args, **kwargs)
+            finally:
+                self._submitting = False
+
+        scheduler.submit = stamped_submit
         return self
 
     def _clock(self) -> float:
+        if self._submitting:
+            return self._vt
         if not self._in_launch:
             self._in_launch = True
             return self._vt

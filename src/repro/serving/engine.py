@@ -24,6 +24,8 @@ from typing import Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.core import cluster as _cluster
@@ -491,29 +493,43 @@ class NonNeuralServeEngine:
         return len(sizes)
 
     def classify(self, X) -> ClassifyResult:
-        """X: (B, d) queries -> per-query prediction + aux evidence."""
-        X = jnp.asarray(X)
-        B = X.shape[0]
+        """X: (B, d) queries -> per-query prediction + aux evidence.
+        Spans: ``engine.classify`` around ``engine.put`` (to the device,
+        padded to buckets), ``engine.dispatch`` (the jitted launches) and
+        ``engine.slice`` (padding rows off, chunks joined)."""
+        B = len(X)
         if B == 0:
             return self._empty()
-        classes, auxes, launches = [], [], 0
-        for lo in range(0, B, self.max_batch):
-            chunk = X[lo: lo + self.max_batch]
-            bucket = self._bucket(chunk.shape[0])
-            pad = bucket - chunk.shape[0]
-            if pad:
-                chunk = jnp.pad(chunk, ((0, pad), (0, 0)))
-            s, p, bn = self._choice(bucket)
-            cls, aux = self._fn_for(s, p, bn)(self._params_for(s), chunk)
-            classes.append(cls[: bucket - pad])
-            auxes.append(aux[: bucket - pad])
-            self.bucket_launches[bucket] = \
-                self.bucket_launches.get(bucket, 0) + 1
-            self.warmed.add(bucket)
-            launches += 1
-        return ClassifyResult(classes=jnp.concatenate(classes),
-                              aux=jnp.concatenate(auxes),
-                              launches=launches,
+        with TraceAnnotation("engine.classify",
+                             bucket=self._bucket(min(B, self.max_batch)),
+                             rows=B):
+            with TraceAnnotation("engine.put"):
+                X = jnp.asarray(X)
+                chunks = []
+                for lo in range(0, B, self.max_batch):
+                    chunk = X[lo: lo + self.max_batch]
+                    bucket = self._bucket(chunk.shape[0])
+                    pad = bucket - chunk.shape[0]
+                    if pad:
+                        chunk = jnp.pad(chunk, ((0, pad), (0, 0)))
+                    chunks.append((chunk, bucket, pad))
+            with TraceAnnotation("engine.dispatch"):
+                outs = []
+                for chunk, bucket, _ in chunks:
+                    s, p, bn = self._choice(bucket)
+                    outs.append(self._fn_for(s, p, bn)(self._params_for(s),
+                                                       chunk))
+                    self.bucket_launches[bucket] = \
+                        self.bucket_launches.get(bucket, 0) + 1
+                    self.warmed.add(bucket)
+            with TraceAnnotation("engine.slice"):
+                classes = jnp.concatenate(
+                    [cls[: bucket - pad]
+                     for (cls, _), (_, bucket, pad) in zip(outs, chunks)])
+                aux = jnp.concatenate(
+                    [a[: bucket - pad]
+                     for (_, a), (_, bucket, pad) in zip(outs, chunks)])
+        return ClassifyResult(classes=classes, aux=aux, launches=len(outs),
                               algorithm=self.algorithm)
 
     # ------------------------------------------------ grouped (multi-tenant)
@@ -566,41 +582,54 @@ class NonNeuralServeEngine:
         power-of-two group bucket (repeating the last model), B pads to
         the query bucket; B beyond ``max_batch`` microbatches along the
         query axis."""
-        Xg = jnp.asarray(Xg)
-        assert Xg.ndim == 3, f"Xg must be (G, B, d), got {Xg.shape}"
-        G, B = Xg.shape[0], Xg.shape[1]
+        assert np.ndim(Xg) == 3, f"Xg must be (G, B, d), got {np.shape(Xg)}"
+        G, B = np.shape(Xg)[:2]
         gb = self._group_bucket(G)
         if G > self._group_bucket(self.max_group):
             raise ValueError(
                 f"{G} models exceed max_group={self.max_group} — split the "
                 f"group (the scheduler's drain does this automatically)")
-        if gb > G:
-            Xg = jnp.concatenate(
-                [Xg, jnp.zeros((gb - G,) + Xg.shape[1:], Xg.dtype)])
-        stacked = self._group_resize(stacked_params, gb)
         fn = self.group_fn()
-        classes, auxes, launches = [], [], 0
-        for lo in range(0, B, self.max_batch):
-            chunk = Xg[:, lo: lo + self.max_batch] if B > self.max_batch \
-                else Xg
-            bucket = self._bucket(chunk.shape[1])
-            pad = bucket - chunk.shape[1]
-            if pad:
-                chunk = jnp.pad(chunk, ((0, 0), (0, pad), (0, 0)))
-            cls, aux = fn(stacked, chunk)
-            if pad:     # no-op slices still dispatch eagerly — skip them
-                cls, aux = cls[:, : bucket - pad], aux[:, : bucket - pad]
-            classes.append(cls)
-            auxes.append(aux)
-            self.group_launches[(gb, bucket)] = \
-                self.group_launches.get((gb, bucket), 0) + 1
-            self.warmed_groups.add((gb, bucket))
-            launches += 1
-        cls = classes[0] if launches == 1 \
-            else jnp.concatenate(classes, axis=1)
-        aux = auxes[0] if launches == 1 else jnp.concatenate(auxes, axis=1)
-        if gb > G:
-            cls, aux = cls[:G], aux[:G]
+        with TraceAnnotation("engine.classify",
+                             bucket=self._bucket(min(B, self.max_batch)),
+                             rows=B, group=gb):
+            with TraceAnnotation("engine.put"):
+                Xg = jnp.asarray(Xg)
+                if gb > G:
+                    Xg = jnp.concatenate(
+                        [Xg, jnp.zeros((gb - G,) + Xg.shape[1:], Xg.dtype)])
+                stacked = self._group_resize(stacked_params, gb)
+                chunks = []
+                for lo in range(0, B, self.max_batch):
+                    chunk = Xg[:, lo: lo + self.max_batch] \
+                        if B > self.max_batch else Xg
+                    bucket = self._bucket(chunk.shape[1])
+                    pad = bucket - chunk.shape[1]
+                    if pad:
+                        chunk = jnp.pad(chunk, ((0, 0), (0, pad), (0, 0)))
+                    chunks.append((chunk, bucket, pad))
+            with TraceAnnotation("engine.dispatch"):
+                outs = []
+                for chunk, bucket, _ in chunks:
+                    outs.append(fn(stacked, chunk))
+                    self.group_launches[(gb, bucket)] = \
+                        self.group_launches.get((gb, bucket), 0) + 1
+                    self.warmed_groups.add((gb, bucket))
+            with TraceAnnotation("engine.slice"):
+                classes, auxes = [], []
+                for (cls, aux), (_, bucket, pad) in zip(outs, chunks):
+                    if pad:     # no-op slices still dispatch eagerly — skip
+                        cls, aux = cls[:, : bucket - pad], \
+                            aux[:, : bucket - pad]
+                    classes.append(cls)
+                    auxes.append(aux)
+                launches = len(outs)
+                cls = classes[0] if launches == 1 \
+                    else jnp.concatenate(classes, axis=1)
+                aux = auxes[0] if launches == 1 \
+                    else jnp.concatenate(auxes, axis=1)
+                if gb > G:
+                    cls, aux = cls[:G], aux[:G]
         return GroupClassifyResult(classes=cls, aux=aux,
                                    launches=launches,
                                    algorithm=self.algorithm)

@@ -15,10 +15,11 @@ coalescing window) and request deadlines are tick counts, so a replayed
 trace is bit-deterministic and the SLO accounting in ``ServingStats``
 (p50/p95/p99 latency, throughput, bucket occupancy, cache hit-rate) can
 be checked against a hand-computed trace.  Wall-clock appears only in
-``batch_time`` (the launch duration, read from an injectable ``clock``
-so a chaos harness can replace it with virtual time), which feeds the
-per-drain ``runtime/straggler.StepTimer`` watch/checkpoint/evict
-escalation.
+``batch_time`` (the launch duration), which feeds the per-drain
+``runtime/straggler.StepTimer`` watch/checkpoint/evict escalation, and in
+``queue_s`` (submit to the start of the serving launch); both read one
+injectable ``clock``, so a chaos harness can replace it with virtual
+time.
 
 Overload is a first-class outcome, not an error path.  Three graceful-
 degradation mechanisms, all OFF by default so the unloaded fast path is
@@ -59,6 +60,7 @@ from typing import Any, Callable, Deque, Dict, List, NamedTuple, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.events import Event, event, straggler_event
 from repro.runtime.straggler import StepTimer
@@ -87,6 +89,7 @@ class RequestResult:
     shed: bool = False
     reason: Optional[str] = None
     tier: str = "full"
+    queue_s: float = 0.0       # clock seconds, submit -> its launch starts
 
 
 @dataclass
@@ -94,6 +97,7 @@ class _Pending:
     request_id: int
     x: np.ndarray              # (d,) query row
     submit_tick: int
+    submit_s: float            # the scheduler's clock at submit()
     deadline: Optional[int]    # relative ticks, None = no SLO
     cache_key: Optional[Any]   # (engine/tenant fingerprint, dtype, bytes)
     model_id: Any = None       # tenant routing key (store-mode schedulers)
@@ -127,10 +131,14 @@ class ServingStats:
     ``shed_reasons``): they never enter ``completed`` or the latency
     pool, so an all-shed window reads nan percentiles and zero
     throughput with a non-zero ``shed`` count — it does not raise.
+
+    ``queue_s`` holds the same SERVED requests' waits on the scheduler's
+    clock (``RequestResult.queue_s``), beside their tick latencies.
     """
 
     def __init__(self):
         self.latencies: List[int] = []     # ticks, per SERVED request
+        self.queue_s: List[float] = []     # clock seconds, per SERVED request
         self.completed = 0
         self.cache_hits = 0
         self.deadline_misses = 0
@@ -138,7 +146,7 @@ class ServingStats:
         self.ticks = 0
         self.occupancies: List[float] = []  # valid rows / bucket, per launch
         self.bucket_launches: Dict[int, int] = {}
-        self.batch_times: List[float] = []
+        self.batch_time_s = 0.0            # summed launch wall time
         self.shed = 0
         self.shed_reasons: Dict[str, int] = {}
         self.tier_launches: Dict[str, int] = {}
@@ -156,7 +164,7 @@ class ServingStats:
         self.occupancies.append(n_valid / bucket)
         self.bucket_launches[bucket] = \
             self.bucket_launches.get(bucket, 0) + 1
-        self.batch_times.append(batch_time)
+        self.batch_time_s += batch_time
         if tier is not None:
             self.tier_launches[tier] = self.tier_launches.get(tier, 0) + 1
             per = self.tier_bucket_launches.setdefault(tier, {})
@@ -174,6 +182,7 @@ class ServingStats:
         self.deadline_misses += r.deadline_missed
         if not r.cache_hit:
             self.latencies.append(r.queue_time)
+            self.queue_s.append(r.queue_s)
             self.tier_served[r.tier] = self.tier_served.get(r.tier, 0) + 1
 
     def observe_shift(self, down: bool) -> None:
@@ -194,11 +203,11 @@ class ServingStats:
 
     def percentile(self, q: float) -> float:
         """Nearest-rank percentile of SERVED-request latency, in ticks."""
-        if not self.latencies:
-            return float("nan")
-        vals = sorted(self.latencies)
-        rank = max(1, int(np.ceil(q * len(vals))))
-        return float(vals[rank - 1])
+        return _nearest_rank(self.latencies, q)
+
+    def queue_percentile(self, q: float) -> float:
+        """Nearest-rank percentile of SERVED-request ``queue_s``."""
+        return _nearest_rank(self.queue_s, q)
 
     @property
     def hit_rate(self) -> float:
@@ -249,7 +258,17 @@ class ServingStats:
             "shed_rate": self.shed_rate,
             "miss_plus_shed_rate": self.miss_plus_shed_rate,
             "downshifts": self.downshifts,
+            "queue_p50_s": self.queue_percentile(0.50),
+            "queue_p95_s": self.queue_percentile(0.95),
         }
+
+
+def _nearest_rank(values, q: float) -> float:
+    if not values:
+        return float("nan")
+    vals = sorted(values)
+    rank = max(1, int(np.ceil(q * len(vals))))
+    return float(vals[rank - 1])
 
 
 class RequestScheduler:
@@ -285,10 +304,20 @@ class RequestScheduler:
         (expiry sheds, ``record_failure`` health rejections) open the
         tenant's breaker and its submits shed with
         ``reason="breaker_open"`` until a half-open probe succeeds.
-      * ``clock`` — the wall-clock source for ``batch_time`` (default
-        ``time.perf_counter``); runtime/chaos.py injects a deterministic
-        virtual clock here so straggler verdicts — and therefore the
-        whole RequestResult stream — replay bit-identically.
+      * ``clock`` — the wall-clock source for ``batch_time`` and
+        ``queue_s`` (default ``time.perf_counter``); runtime/chaos.py
+        injects a deterministic virtual clock here so straggler verdicts
+        — and therefore the whole RequestResult stream — replay
+        bit-identically.  It is read at each queued submit and around
+        each launch.
+
+    Program spans (``jax.profiler.TraceAnnotation``, recorded only while
+    a profiler trace runs): a launching drain opens ``scheduler.drain``
+    (ids ``launch``, ``bucket``, ``rows``, ``tier``; grouped drains
+    ``launch`` and ``tier``) around ``scheduler.coalesce`` (pop, stack, pad), the
+    engine's own ``engine.classify``, ``scheduler.ready`` (waiting for
+    the device), ``scheduler.fetch`` (device-to-host copies) and
+    ``scheduler.scatter`` (per-request results, stats, cache).
 
     The engine must be warmed first (``engine.warmup_buckets(d)`` /
     ``engine.warmup(X)``; store mode: ``engine.warmup_groups``): drains
@@ -475,6 +504,7 @@ class RequestScheduler:
             return rid
         self.queue.append(_Pending(request_id=rid, x=row,
                                    submit_tick=self.tick,
+                                   submit_s=self.clock(),
                                    deadline=deadline, cache_key=key,
                                    model_id=model_id))
         return rid
@@ -596,48 +626,59 @@ class RequestScheduler:
         tier = self._current_tier()
         n = min(len(self.queue), tier.capacity)
         bucket = self._pick_bucket(n, tier.warmed)
-        taken = [self.queue.popleft() for _ in range(min(n, bucket))]
-        batch = np.stack([p.x for p in taken])
-        if batch.shape[0] < bucket:      # pad so the engine reuses the
-            batch = np.concatenate(      # compiled bucket-sized executable
-                [batch, np.zeros((bucket - batch.shape[0], batch.shape[1]),
-                                 batch.dtype)])
-        t0 = self.clock()
-        res = tier.engine.classify(batch)
-        jax.block_until_ready(res.classes)
-        batch_time = self.clock() - t0
+        with TraceAnnotation("scheduler.drain", launch=self.stats.launches,
+                             bucket=bucket, rows=min(n, bucket),
+                             tier=tier.name):
+            with TraceAnnotation("scheduler.coalesce"):
+                taken = [self.queue.popleft() for _ in range(min(n, bucket))]
+                batch = np.stack([p.x for p in taken])
+                if batch.shape[0] < bucket:  # pad so the engine reuses the
+                    batch = np.concatenate(  # compiled bucket executable
+                        [batch, np.zeros((bucket - batch.shape[0],
+                                          batch.shape[1]), batch.dtype)])
+            t0 = self.clock()
+            res = tier.engine.classify(batch)
+            with TraceAnnotation("scheduler.ready"):
+                jax.block_until_ready(res.classes)
+            batch_time = self.clock() - t0
 
-        verdict = self.timer.record(self.host, batch_time)
-        straggling = self._note_verdict(verdict)
-        self.stats.observe_launch(bucket, len(taken), batch_time,
-                                  tier=tier.name)
+            verdict = self.timer.record(self.host, batch_time)
+            straggling = self._note_verdict(verdict)
+            self.stats.observe_launch(bucket, len(taken), batch_time,
+                                      tier=tier.name)
 
-        classes = np.asarray(res.classes)
-        aux = np.asarray(res.aux)
-        for i, p in enumerate(taken):
-            queue_time = self.tick - p.submit_tick
-            missed = p.deadline is not None and queue_time > p.deadline
-            r = RequestResult(request_id=p.request_id,
-                              prediction=classes[i], aux=aux[i],
-                              queue_time=queue_time, batch_time=batch_time,
-                              bucket=bucket, deadline_missed=missed,
-                              tier=tier.name)
-            self.results[p.request_id] = r
-            self.stats.observe(r)
-            if self.degrade is not None:
-                self.degrade.note_latency(queue_time)
-            if p.cache_key is not None and tier.cache_ok:
-                # copy the rows: views would pin the launch's whole
-                # bucket-sized arrays for the cache entry's lifetime;
-                # degraded-tier answers are approximations and must never
-                # be replayed as exact results once the tier recovers
-                self._cache[p.cache_key] = (classes[i].copy(),
-                                            aux[i].copy())
-                self._cache.move_to_end(p.cache_key)
-                while len(self._cache) > self.cache_size:
-                    self._cache.popitem(last=False)
-            out.append(r)
-        self._observe_degrade(straggler=straggling, sheds=sheds_now)
+            with TraceAnnotation("scheduler.fetch"):
+                classes = np.asarray(res.classes)
+                aux = np.asarray(res.aux)
+            with TraceAnnotation("scheduler.scatter"):
+                for i, p in enumerate(taken):
+                    queue_time = self.tick - p.submit_tick
+                    missed = p.deadline is not None \
+                        and queue_time > p.deadline
+                    r = RequestResult(request_id=p.request_id,
+                                      prediction=classes[i], aux=aux[i],
+                                      queue_time=queue_time,
+                                      batch_time=batch_time, bucket=bucket,
+                                      deadline_missed=missed,
+                                      tier=tier.name,
+                                      queue_s=t0 - p.submit_s)
+                    self.results[p.request_id] = r
+                    self.stats.observe(r)
+                    if self.degrade is not None:
+                        self.degrade.note_latency(queue_time)
+                    if p.cache_key is not None and tier.cache_ok:
+                        # copy the rows: views would pin the launch's whole
+                        # bucket-sized arrays for the cache entry's
+                        # lifetime; degraded-tier answers are
+                        # approximations and must never be replayed as
+                        # exact results once the tier recovers
+                        self._cache[p.cache_key] = (classes[i].copy(),
+                                                    aux[i].copy())
+                        self._cache.move_to_end(p.cache_key)
+                        while len(self._cache) > self.cache_size:
+                            self._cache.popitem(last=False)
+                    out.append(r)
+            self._observe_degrade(straggler=straggling, sheds=sheds_now)
         return out
 
     def _drain_grouped(self, force: bool) -> List[RequestResult]:
@@ -663,97 +704,108 @@ class RequestScheduler:
         if not ready:
             self._observe_degrade(straggler=False, sheds=sheds_now)
             return out
-        gmax = max(g for g, _ in self.warmed_groups)
-        if self.degrade is not None:
-            gmax = max(1, gmax >> self.degrade.group_shift)
-        bmax = max(b for _, b in self.warmed_groups)
-        budget = min(len(self.queue), self.max_batch)
-        taken_by: "OrderedDict[Any, List[_Pending]]" = OrderedDict()
-        deferred: List[_Pending] = []
-        count = 0
-        while self.queue and count < budget:
-            p = self.queue.popleft()
-            rows = taken_by.get(p.model_id)
-            if rows is None:
-                if len(taken_by) >= gmax:
-                    deferred.append(p)
-                    continue
-                rows = taken_by[p.model_id] = []
-            if len(rows) >= bmax:
-                deferred.append(p)
-                continue
-            rows.append(p)
-            count += 1
-        # deferred requests are older than everything still queued: back
-        # to the front, original order preserved
-        self.queue.extendleft(reversed(deferred))
-        ids = list(taken_by)
-        g = len(ids)
-        gb = min(gg for gg, _ in self.warmed_groups if gg >= g)
-        maxc = max(len(rows) for rows in taken_by.values())
-        covering = sorted(b for gg, b in self.warmed_groups
-                          if gg == gb and b >= maxc)
-        bucket = covering[0] if covering else \
-            max(b for gg, b in self.warmed_groups if gg == gb)
-        # pad the group by repeating tenant 0 — same compiled cell, and
-        # the padded lanes' all-zero rows are sliced off below
-        padded_ids = ids + [ids[0]] * (gb - g)
-        stacked, _gens = self.store.group(padded_ids)
-        d = taken_by[ids[0]][0].x.shape[0]
-        Xg = np.zeros((gb, bucket, d), np.float32)
-        for gi, mid in enumerate(ids):
-            for bi, p in enumerate(taken_by[mid]):
-                Xg[gi, bi] = p.x
-        t0 = self.clock()
-        res = self.engine.classify_group(stacked, Xg)
-        jax.block_until_ready(res.classes)
-        batch_time = self.clock() - t0
-
-        verdict = self.timer.record(self.host, batch_time)
-        straggling = self._note_verdict(verdict)
-        # global occupancy is valid rows over the whole launch footprint
-        # (group lanes x bucket rows) — the multi-tenant analogue of the
-        # paper's §5.3 core-utilization accounting
         tname = self.degrade.tier_name() if self.degrade is not None \
             else None
-        self.stats.observe_launch(gb * bucket, count, batch_time,
-                                  tier=tname)
-
-        classes = np.asarray(res.classes)
-        aux = np.asarray(res.aux)
-        for gi, mid in enumerate(ids):
-            rows = taken_by[mid]
-            tstats = self._tenant_stats(mid)
-            tstats.observe_launch(bucket, len(rows), batch_time)
-            br = self.breakers.get(mid) \
-                if self.breaker_config is not None else None
-            for bi, p in enumerate(rows):
-                queue_time = self.tick - p.submit_tick
-                missed = p.deadline is not None and queue_time > p.deadline
-                r = RequestResult(request_id=p.request_id,
-                                  prediction=classes[gi, bi],
-                                  aux=aux[gi, bi], queue_time=queue_time,
-                                  batch_time=batch_time, bucket=bucket,
-                                  deadline_missed=missed,
-                                  tier=tname or "full")
-                self.results[p.request_id] = r
-                self.stats.observe(r)
-                tstats.observe(r)
+        with TraceAnnotation("scheduler.drain", launch=self.stats.launches,
+                             tier=tname or "full"):
+            with TraceAnnotation("scheduler.coalesce"):
+                gmax = max(g for g, _ in self.warmed_groups)
                 if self.degrade is not None:
-                    self.degrade.note_latency(queue_time)
-                if br is not None:
-                    kind = br.success(self.tick)
-                    if kind:
-                        self.events.append(event(
-                            kind, self.tick, "scheduler", model=str(mid)))
-                if p.cache_key is not None:
-                    self._cache[p.cache_key] = (classes[gi, bi].copy(),
-                                                aux[gi, bi].copy())
-                    self._cache.move_to_end(p.cache_key)
-                    while len(self._cache) > self.cache_size:
-                        self._cache.popitem(last=False)
-                out.append(r)
-        self._observe_degrade(straggler=straggling, sheds=sheds_now)
+                    gmax = max(1, gmax >> self.degrade.group_shift)
+                bmax = max(b for _, b in self.warmed_groups)
+                budget = min(len(self.queue), self.max_batch)
+                taken_by: "OrderedDict[Any, List[_Pending]]" = OrderedDict()
+                deferred: List[_Pending] = []
+                count = 0
+                while self.queue and count < budget:
+                    p = self.queue.popleft()
+                    rows = taken_by.get(p.model_id)
+                    if rows is None:
+                        if len(taken_by) >= gmax:
+                            deferred.append(p)
+                            continue
+                        rows = taken_by[p.model_id] = []
+                    if len(rows) >= bmax:
+                        deferred.append(p)
+                        continue
+                    rows.append(p)
+                    count += 1
+                # deferred requests are older than everything still queued:
+                # back to the front, original order preserved
+                self.queue.extendleft(reversed(deferred))
+                ids = list(taken_by)
+                g = len(ids)
+                gb = min(gg for gg, _ in self.warmed_groups if gg >= g)
+                maxc = max(len(rows) for rows in taken_by.values())
+                covering = sorted(b for gg, b in self.warmed_groups
+                                  if gg == gb and b >= maxc)
+                bucket = covering[0] if covering else \
+                    max(b for gg, b in self.warmed_groups if gg == gb)
+                # pad the group by repeating tenant 0 — same compiled cell,
+                # and the padded lanes' all-zero rows are sliced off below
+                padded_ids = ids + [ids[0]] * (gb - g)
+                stacked, _gens = self.store.group(padded_ids)
+                d = taken_by[ids[0]][0].x.shape[0]
+                Xg = np.zeros((gb, bucket, d), np.float32)
+                for gi, mid in enumerate(ids):
+                    for bi, p in enumerate(taken_by[mid]):
+                        Xg[gi, bi] = p.x
+            t0 = self.clock()
+            res = self.engine.classify_group(stacked, Xg)
+            with TraceAnnotation("scheduler.ready"):
+                jax.block_until_ready(res.classes)
+            batch_time = self.clock() - t0
+
+            verdict = self.timer.record(self.host, batch_time)
+            straggling = self._note_verdict(verdict)
+            # global occupancy is valid rows over the whole launch
+            # footprint (group lanes x bucket rows) — the multi-tenant
+            # analogue of the paper's §5.3 core-utilization accounting
+            self.stats.observe_launch(gb * bucket, count, batch_time,
+                                      tier=tname)
+
+            with TraceAnnotation("scheduler.fetch"):
+                classes = np.asarray(res.classes)
+                aux = np.asarray(res.aux)
+            with TraceAnnotation("scheduler.scatter"):
+                for gi, mid in enumerate(ids):
+                    rows = taken_by[mid]
+                    tstats = self._tenant_stats(mid)
+                    tstats.observe_launch(bucket, len(rows), batch_time)
+                    br = self.breakers.get(mid) \
+                        if self.breaker_config is not None else None
+                    for bi, p in enumerate(rows):
+                        queue_time = self.tick - p.submit_tick
+                        missed = p.deadline is not None \
+                            and queue_time > p.deadline
+                        r = RequestResult(request_id=p.request_id,
+                                          prediction=classes[gi, bi],
+                                          aux=aux[gi, bi],
+                                          queue_time=queue_time,
+                                          batch_time=batch_time,
+                                          bucket=bucket,
+                                          deadline_missed=missed,
+                                          tier=tname or "full",
+                                          queue_s=t0 - p.submit_s)
+                        self.results[p.request_id] = r
+                        self.stats.observe(r)
+                        tstats.observe(r)
+                        if self.degrade is not None:
+                            self.degrade.note_latency(queue_time)
+                        if br is not None:
+                            kind = br.success(self.tick)
+                            if kind:
+                                self.events.append(event(
+                                    kind, self.tick, "scheduler",
+                                    model=str(mid)))
+                        if p.cache_key is not None:
+                            self._cache[p.cache_key] = (
+                                classes[gi, bi].copy(), aux[gi, bi].copy())
+                            self._cache.move_to_end(p.cache_key)
+                            while len(self._cache) > self.cache_size:
+                                self._cache.popitem(last=False)
+                        out.append(r)
+            self._observe_degrade(straggler=straggling, sheds=sheds_now)
         return out
 
     def flush(self) -> List[RequestResult]:
