@@ -39,7 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ops import pad_dim, resolve_interpret
-from repro.kernels.topk_select import merge_topk
+from repro.kernels.topk_select import merge_topk, sort_topk
 
 _IMAX = jnp.iinfo(jnp.int32).max
 _LANES = 128                   # candidate-block multiple (one vreg of lanes)
@@ -134,12 +134,14 @@ def _adc_topk_kernel(lut_ref, codes_ref, ids_ref, vals_ref, idx_ref,
     # equal distances, then the smallest lane, i.e. the smallest global
     # candidate position — the same stable rule as lax.top_k
     lane = jax.lax.broadcasted_iota(jnp.int32, (q, bl), 1)
-    v, ix = merge_topk(acc_v[...], acc_i[...], dist * bl + lane, i * bl, k,
-                       fill=_IMAX, packed_bn=bl)
+    v, ix, _ = merge_topk(acc_v[...], acc_i[...], dist * bl + lane, i * bl,
+                          k, fill=_IMAX, packed_bn=bl)
     acc_v[...] = v
     acc_i[...] = ix
-    vals_ref[...] = v
-    idx_ref[...] = ix
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _store():
+        vals_ref[...], idx_ref[...] = sort_topk(v, ix, fill=_IMAX)
 
 
 def _adc_topk_call(lut, codes_t, ids, k: int, *, bl: int, m: int,

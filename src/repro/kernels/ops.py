@@ -103,13 +103,16 @@ def pairwise_sq_dist(a, c, *, bn: int = 256, interpret: bool | None = None):
     return out[:N]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "bn", "stats",
+                                             "interpret"))
 def distance_topk(a, c, k: int, *, bn: int | None = None,
-                  interpret: bool | None = None):
+                  stats: bool = False, interpret: bool | None = None):
     """Fused kNN hot path: A (N, d) data, C (Q, d) queries -> k nearest rows
     per query as (values (Q, k), global indices (Q, k)), ascending.  The
     (N, Q) distance matrix never leaves VMEM (DESIGN.md §3); bn=None picks
-    the largest streaming block that fits the VMEM budget."""
+    the largest streaming block that fits the VMEM budget.  ``stats`` adds
+    a third result, int32 (merge passes run, tiles), counted over the
+    queries padded to a multiple of 8."""
     interpret = resolve_interpret(interpret)
     N, d = a.shape
     Q = c.shape[0]
@@ -119,9 +122,9 @@ def distance_topk(a, c, k: int, *, bn: int | None = None,
     bn = clamp_block(bn, N)
     ap = pad_dim(a, bn, 0)
     cp = pad_dim(c, 8, 0)
-    vals, idx = _dtopk.distance_topk(ap, cp, k, bn=bn, n_valid=N,
-                                     interpret=interpret)
-    return vals[:Q], idx[:Q]
+    out = _dtopk.distance_topk(ap, cp, k, bn=bn, n_valid=N, stats=stats,
+                               interpret=interpret)
+    return (out[0][:Q], out[1][:Q]) + tuple(out[2:])
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))

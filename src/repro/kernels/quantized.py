@@ -41,7 +41,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ops import pad_dim, resolve_interpret
-from repro.kernels.topk_select import merge_topk
+from repro.kernels.topk_select import merge_topk, sort_topk
 
 _IMAX = jnp.iinfo(jnp.int32).max
 _QMAX = 127                     # symmetric int8 lattice: values in [-127, 127]
@@ -173,12 +173,14 @@ def _quant_topk_kernel(a_ref, c_ref, vals_ref, idx_ref, acc_v, acc_i,
     # fold the tile into the running k-smallest: accumulator first on
     # equal distances, then the smallest lane — the smallest global row
     # index, the same stable rule as the fp32 fused kernel and lax.top_k
-    v, ix = merge_topk(acc_v[...], acc_i[...], key, i * bn, k, fill=_IMAX,
-                       packed_bn=bn)
+    v, ix, _ = merge_topk(acc_v[...], acc_i[...], key, i * bn, k,
+                          fill=_IMAX, packed_bn=bn)
     acc_v[...] = v
     acc_i[...] = ix
-    vals_ref[...] = v
-    idx_ref[...] = ix
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _store():
+        vals_ref[...], idx_ref[...] = sort_topk(v, ix, fill=_IMAX)
 
 
 def _quant_topk_call(ap, cp, k: int, *, bn: int, n_valid: int, off: int,

@@ -6,6 +6,11 @@ VPU as k passes of vectorised min+mask over a row block held in VMEM (the
 scalar swap loop of Selection Sort is hostile to 8x128 vregs; the masked-min
 pass has identical asymptotics and full lane utilisation; DESIGN.md §2).
 
+The streaming merge (``merge_topk``) runs those passes only while they can
+change the answer: once a query's running k-th best is tighter than a whole
+tile, the tile costs one min sweep.  Its set stays unsorted until
+``sort_topk`` orders it once, after the last tile.
+
 Mosaic cannot store to a column picked by a loop index (``ref[:, j]``
 needs a lane offset it can prove is a multiple of 128), so every pass
 writes its result into a (rows, k) value with ``where(col == j, ...)`` and
@@ -29,39 +34,84 @@ def _set_col(out, col, j, v):
 
 def merge_topk(acc_v, acc_i, tile, base, k: int, *, fill,
                packed_bn: int | None = None):
-    """Fold a (Q, bn) tile into a sorted running (Q, k) k-smallest list.
+    """Fold a (Q, bn) tile into an unsorted running (Q, k) k-smallest set.
 
-    ``acc_v``/``acc_i`` hold values and global indices, ascending, with
-    every index smaller than the tile's (``base + lane``).  ``tile`` holds
-    raw values, or with ``packed_bn`` unique int32 keys ``value * packed_bn
-    + lane``.  Each of the k passes takes the smaller of the two list
-    heads; ties go to the accumulator, then to the first lane, so the
-    result keeps the smallest-global-index-first rule of a stable sort over
-    ``[accumulator | tile]``.  ``fill`` marks a taken entry.  Returns the
-    new (values, indices)."""
+    ``acc_v``/``acc_i`` hold values and global indices, in any order, every
+    real index smaller than the tile's (``base + lane``); an unfilled slot
+    holds ``(fill, 0)``.  ``tile`` holds raw values, or with ``packed_bn``
+    unique int32 keys ``value * packed_bn + lane``.  A pass runs only while
+    some query's smallest remaining tile entry beats the worst entry of its
+    set: that query's worst is replaced by its tile minimum (first lane on
+    ties), the lane is masked with ``fill``, and the worst is found again.
+    The worst is the largest under the total order (value, index), and the
+    comparison is strict, so a tie keeps the accumulator's smaller index:
+    the set stays the k smallest of ``[accumulator | tile]`` under that
+    order, the set a stable sort keeps; ``sort_topk`` orders it.  (A masked
+    packed key decodes above every real value, so it fills only an unfilled
+    slot, and the next real entry displaces it.)  At most ``min(k, bn)``
+    passes run; a tile that improves no query costs one min sweep.
+    Returns the new (values, indices) and the number of passes run."""
     q, bn = tile.shape
     acol = jax.lax.broadcasted_iota(jnp.int32, (q, k), 1)
     tcol = jax.lax.broadcasted_iota(jnp.int32, (q, bn), 1)
 
-    def body(j, carry):
-        av, tv, ov, oi = carry
-        ma = jnp.min(av, axis=1)                              # (Q,)
-        pa = jnp.min(jnp.where(av == ma[:, None], acol, k), axis=1)
-        if packed_bn is None:
-            mt = jnp.min(tv, axis=1)
-            pt = jnp.min(jnp.where(tv == mt[:, None], tcol, bn), axis=1)
-        else:
-            key = jnp.min(tv, axis=1)
-            mt, pt = key // packed_bn, key % packed_bn
-        from_acc = ma <= mt
-        ia = jnp.sum(jnp.where(acol == pa[:, None], acc_i, 0), axis=1)
-        ov = _set_col(ov, acol, j, jnp.where(from_acc, ma, mt))
-        oi = _set_col(oi, acol, j, jnp.where(from_acc, ia, base + pt))
-        av = jnp.where(from_acc[:, None] & (acol == pa[:, None]), fill, av)
-        tv = jnp.where(~from_acc[:, None] & (tcol == pt[:, None]), fill, tv)
-        return av, tv, ov, oi
+    def worst(av, ai):
+        wv = jnp.max(av, axis=1)
+        at_wv = av == wv[:, None]
+        wi = jnp.max(jnp.where(at_wv, ai, -1), axis=1)
+        wp = jnp.min(jnp.where(at_wv & (ai == wi[:, None]), acol, k), axis=1)
+        return wv, wp
 
-    _, _, ov, oi = jax.lax.fori_loop(0, k, body, (acc_v, tile, acc_v, acc_i))
+    def value(m):                       # a row's tile minimum -> its value
+        return m if packed_bn is None else m // packed_bn
+
+    def cond(carry):
+        it, _, _, _, m, wv, _ = carry
+        return (it < min(k, bn)) & jnp.any(value(m) < wv)
+
+    def body(carry):
+        it, av, ai, tv, m, wv, wp = carry
+        mt = value(m)
+        if packed_bn is None:
+            pt = jnp.min(jnp.where(tv == m[:, None], tcol, bn), axis=1)
+        else:
+            pt = m % packed_bn
+        put = (mt < wv)[:, None] & (acol == wp[:, None])
+        av = jnp.where(put, mt[:, None], av)
+        ai = jnp.where(put, (base + pt)[:, None], ai)
+        # a row that took nothing has no remaining entry below its worst,
+        # so masking its minimum too loses nothing
+        tv = jnp.where(tcol == pt[:, None], fill, tv)
+        wv, wp = worst(av, ai)
+        return it + 1, av, ai, tv, jnp.min(tv, axis=1), wv, wp
+
+    passes, av, ai, _, _, _, _ = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), acc_v, acc_i, tile, jnp.min(tile, axis=1))
+        + worst(acc_v, acc_i))
+    return av, ai, passes
+
+
+def sort_topk(v, ix, *, fill):
+    """Order a (Q, k) set from ``merge_topk`` ascending by (value, index),
+    as k masked-min passes over the set; ``fill`` is at least every
+    value."""
+    q, k = v.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, k), 1)
+    imax = jnp.iinfo(jnp.int32).max
+
+    def body(j, carry):
+        v, ix, ov, oi = carry
+        m = jnp.min(v, axis=1)
+        at_m = v == m[:, None]
+        mi = jnp.min(jnp.where(at_m, ix, imax), axis=1)
+        p = jnp.min(jnp.where(at_m & (ix == mi[:, None]), col, k), axis=1)
+        ov = _set_col(ov, col, j, m)
+        oi = _set_col(oi, col, j, mi)
+        taken = col == p[:, None]
+        return (jnp.where(taken, fill, v), jnp.where(taken, imax, ix),
+                ov, oi)
+
+    _, _, ov, oi = jax.lax.fori_loop(0, k, body, (v, ix, v, ix))
     return ov, oi
 
 

@@ -1,7 +1,9 @@
 """Fused distance->top-k streaming kernel: bit-equivalence vs the two-pass
 composition (pairwise_sq_dist + topk_smallest), oracle parity, tie
-semantics, the batched kNN / fused K-Means paths built on it, the serving
-engine wiring, the matmul block-clamp regression, and the HBM bytes A/B."""
+semantics, the early-exit merge against the fixed-pass merge it replaced
+and its pass counter, the batched kNN / fused K-Means paths built on it,
+the serving engine wiring, the matmul block-clamp regression, and the HBM
+bytes A/B."""
 import sys
 from pathlib import Path
 
@@ -15,7 +17,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from conftest import synth_blobs
 from repro.core import kmeans as KM
 from repro.core import knn as KNN
-from repro.kernels import ops, ref
+from repro.kernels import ann as ann_kernels
+from repro.kernels import distance_topk as dtopk_kernels
+from repro.kernels import ops, quantized, ref
 from repro.serving import KNNServeEngine
 
 KEY = jax.random.PRNGKey(11)
@@ -93,6 +97,169 @@ def test_fused_padded_rows_never_selected():
     c = jnp.zeros((2, 4))                       # pad rows would win unmasked
     _, gi = ops.distance_topk(a, c, 5, bn=8)    # pads 13 -> 16
     assert np.asarray(gi).max() < 13
+
+
+# ------------------------------------------------------------ early-exit merge
+
+
+def _fixed_pass_merge(acc_v, acc_i, tile, base, k, *, fill, packed_bn=None):
+    """The merge the early exit replaced, kept as the bit-identity oracle:
+    k head-to-head passes over a sorted accumulator and the tile, in every
+    tile, ties to the accumulator and then the first lane.  Returns a pass
+    count of 0, so a kernel traced with it is told apart by its counter."""
+    q, bn = tile.shape
+    acol = jax.lax.broadcasted_iota(jnp.int32, (q, k), 1)
+    tcol = jax.lax.broadcasted_iota(jnp.int32, (q, bn), 1)
+
+    def body(j, carry):
+        av, tv, ov, oi = carry
+        ma = jnp.min(av, axis=1)
+        pa = jnp.min(jnp.where(av == ma[:, None], acol, k), axis=1)
+        if packed_bn is None:
+            mt = jnp.min(tv, axis=1)
+            pt = jnp.min(jnp.where(tv == mt[:, None], tcol, bn), axis=1)
+        else:
+            key = jnp.min(tv, axis=1)
+            mt, pt = key // packed_bn, key % packed_bn
+        from_acc = ma <= mt
+        ia = jnp.sum(jnp.where(acol == pa[:, None], acc_i, 0), axis=1)
+        ov = jnp.where(acol == j, jnp.where(from_acc, ma, mt)[:, None], ov)
+        oi = jnp.where(acol == j,
+                       jnp.where(from_acc, ia, base + pt)[:, None], oi)
+        av = jnp.where(from_acc[:, None] & (acol == pa[:, None]), fill, av)
+        tv = jnp.where(~from_acc[:, None] & (tcol == pt[:, None]), fill, tv)
+        return av, tv, ov, oi
+
+    _, _, ov, oi = jax.lax.fori_loop(0, k, body, (acc_v, tile, acc_v, acc_i))
+    return ov, oi, jnp.int32(0)
+
+
+def _with_fixed_pass_merge(monkeypatch, fn, *args, **kw):
+    """Run a jitted kernel wrapper's body with the fixed-pass merge in every
+    streaming top-k kernel; the body is traced anew, not taken from the
+    wrapper's jit cache."""
+    traced = []
+
+    def merge(*a, **kk):
+        traced.append(True)
+        return _fixed_pass_merge(*a, **kk)
+
+    with monkeypatch.context() as mp:
+        for mod in (dtopk_kernels, ann_kernels, quantized):
+            mp.setattr(mod, "merge_topk", merge)
+        out = fn.__wrapped__(*args, **kw)
+    assert traced
+    return out
+
+
+def _int_rows(seed, n, d, hi):
+    """Integer-valued rows: every distance is exact in fp32 whatever the
+    order of the sums, and small ranges give many exact ties."""
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.integers(0, hi, size=(n, d)), jnp.float32)
+
+
+def _merge_case(name):
+    """(rows, queries, k, bn) of one early-exit case."""
+    if name == "ties":               # ties inside tiles and across tiles
+        return _int_rows(1, 300, 4, 3), _int_rows(2, 5, 4, 3), 8, 16
+    if name == "decreasing":         # each row nearer: every tile takes k
+        n = 200
+        a = jnp.zeros((n, 3)).at[:, 0].set(jnp.arange(n, 0, -1.0))
+        return a, jnp.stack([jnp.zeros(3), -jnp.ones(3)]), 6, 8
+    if name == "k_over_bn":          # a tile holds fewer rows than k
+        return _int_rows(3, 150, 5, 6), _int_rows(4, 4, 5, 6), 20, 8
+    if name == "n_valid":            # 123 rows padded to 128, zero rows
+        return _int_rows(5, 123, 6, 9), _int_rows(6, 3, 6, 9), 10, 64
+    if name == "zero_queries":       # all-zero queries, as the engine pads
+        c = jnp.zeros((3, 4)).at[1].set(jnp.ones(4))
+        return _int_rows(7, 250, 4, 4), c, 7, 32
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("case", ["ties", "decreasing", "k_over_bn",
+                                  "n_valid", "zero_queries"])
+def test_early_exit_merge_bit_equal_to_fixed_pass(case, monkeypatch):
+    a, c, k, bn = _merge_case(case)
+    gv, gi = ops.distance_topk(a, c, k, bn=bn)
+    ov, oi, (passes, _) = _with_fixed_pass_merge(
+        monkeypatch, ops.distance_topk, a, c, k, bn=bn, stats=True,
+        interpret=True)
+    assert int(passes) == 0                  # the oracle's kernel ran
+    tv, ti = _two_pass(a, c, k)
+    for want_v, want_i in ((ov, oi), (tv, ti)):
+        np.testing.assert_array_equal(np.asarray(gv), np.asarray(want_v))
+        np.testing.assert_array_equal(np.asarray(gi), np.asarray(want_i))
+
+
+def _adc_k100(bl):
+    """ADC candidates with ties (a narrow LUT) and invalid ids (-1, scored
+    at the DMAX sentinel), k=100 across several blocks."""
+    rng = np.random.default_rng(bl)
+    Q, L, m, n_codes = 5, 300, 4, 16
+    qlut = jnp.asarray(rng.integers(0, 6, size=(Q, m * n_codes)), jnp.int32)
+    codes = jnp.asarray(rng.integers(0, n_codes, size=(Q, L, m)) - 128,
+                        jnp.int8)
+    ids = jnp.asarray(rng.integers(-1, 3, size=(Q, L)), jnp.int32)
+    return qlut, codes, ids
+
+
+@pytest.mark.parametrize("kernel", ["adc_topk-bl8", "adc_topk-bl128",
+                                    "distance_topk_q8"])
+def test_early_exit_packed_merge_bit_equal_at_k100(kernel, monkeypatch):
+    """The packed-key path: unique int32 keys, sentinels in value space
+    (ADC's DMAX for invalid ids; IMAX keys on the q8 kernel's padded
+    rows)."""
+    if kernel.startswith("adc_topk"):
+        fn, ref_fn = ann_kernels.adc_topk, ann_kernels.ref_adc_topk
+        bl = int(kernel.split("bl")[1])
+        args, kw = _adc_k100(bl), {"bl": bl}
+    else:
+        fn, ref_fn = quantized.distance_topk_q8, quantized.ref_distance_topk_q8
+        rng = np.random.default_rng(9)
+        args = tuple(jnp.asarray(rng.integers(-3, 4, size=s), jnp.int8)
+                     for s in ((500, 16), (4, 16)))
+        kw = {"bn": 32}                          # 500 rows padded to 512
+    gv, gi = fn(*args, 100, **kw)
+    ov, oi = _with_fixed_pass_merge(monkeypatch, fn, *args, 100,
+                                    interpret=True, **kw)
+    rv, ri = ref_fn(*args, 100)
+    for want_v, want_i in ((ov, oi), (rv, ri)):
+        np.testing.assert_array_equal(np.asarray(gv), np.asarray(want_v))
+        np.testing.assert_array_equal(np.asarray(gi), np.asarray(want_i))
+
+
+def _passes_needed(a, c, k, bn):
+    """Merge passes an exact early-exit merge needs: per tile, the largest
+    over queries of the tile entries strictly below that query's running
+    k-th value, taken in ascending order, so at most k."""
+    dist = ((np.asarray(c, np.float64)[:, None]
+             - np.asarray(a, np.float64)[None]) ** 2).sum(-1)
+    best = [[] for _ in range(dist.shape[0])]      # (value, index) pairs
+    total = 0
+    for lo in range(0, dist.shape[1], bn):
+        need = 0
+        for q, row in enumerate(dist[:, lo:lo + bn]):
+            tile = [(v, lo + j) for j, v in enumerate(row)]
+            best[q] = sorted(best[q] + tile)[:k]
+            need = max(need, sum(ix >= lo for _, ix in best[q]))
+        total += need
+    return total
+
+
+def test_merge_pass_counter_matches_numpy():
+    a, c, k, bn = _int_rows(11, 333, 4, 5), _int_rows(12, 8, 4, 5), 6, 32
+    gv, gi, stats = ops.distance_topk(a, c, k, bn=bn, stats=True)
+    assert np.asarray(stats).tolist() == [_passes_needed(a, c, k, bn), 11]
+    # off by default: the same answers, a program without the counter
+    pv, pi = ops.distance_topk(a, c, k, bn=bn, stats=False)
+    np.testing.assert_array_equal(np.asarray(gv), np.asarray(pv))
+    np.testing.assert_array_equal(np.asarray(gi), np.asarray(pi))
+    text = ops.distance_topk.lower(a, c, k, bn=bn).as_text()
+    assert text == ops.distance_topk.lower(a, c, k, bn=bn,
+                                           stats=False).as_text()
+    assert text != ops.distance_topk.lower(a, c, k, bn=bn,
+                                           stats=True).as_text()
 
 
 @pytest.mark.parametrize("n,d,kc", [(100, 21, 3), (999, 8, 7), (64, 4, 2)])

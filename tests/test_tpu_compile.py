@@ -60,6 +60,17 @@ KERNELS = {
     "distance_topk": (
         lambda a, c: ops.distance_topk(a, c, K, interpret=False),
         [((1 << 20, D), jnp.float32), ((Q, D), jnp.float32)]),
+    # the serve buckets of one query and of the open-loop cells
+    "distance_topk-q8rows": (
+        lambda a, c: ops.distance_topk(a, c, K, interpret=False),
+        [((1 << 20, D), jnp.float32), ((8, D), jnp.float32)]),
+    "distance_topk-q64rows": (
+        lambda a, c: ops.distance_topk(a, c, K, interpret=False),
+        [((1 << 20, D), jnp.float32), ((64, D), jnp.float32)]),
+    # the merge-pass counter: one more output, in SMEM
+    "distance_topk-stats": (
+        lambda a, c: ops.distance_topk(a, c, K, stats=True, interpret=False),
+        [((1 << 20, D), jnp.float32), ((Q, D), jnp.float32)]),
     "topk_smallest": (
         lambda x: ops.topk_smallest(x, K, interpret=False),
         [((Q, 4096), jnp.float32)]),
